@@ -654,7 +654,6 @@ def inject_sharded(key, store: CIMStore, ber, field: str = "full", *, mesh,
     """
     if isinstance(ber, (int, float)) and ber <= 0.0:
         return store
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels.fault_inject.ops import ber_to_threshold
 
@@ -699,8 +698,8 @@ def inject_sharded(key, store: CIMStore, ber, field: str = "full", *, mesh,
 
     pspecs = store_plane_specs(store, axis, dim)
     rt_specs = jax.tree_util.tree_map(lambda _: P(), rt)
-    flipped = shard_map(local, mesh=mesh, in_specs=(pspecs, rt_specs),
-                        out_specs=pspecs, check_rep=False)(planes, rt)
+    flipped = jax.shard_map(local, mesh=mesh, in_specs=(pspecs, rt_specs),
+                            out_specs=pspecs, check_vma=False)(planes, rt)
     return _restore_planes(store, flipped)
 
 

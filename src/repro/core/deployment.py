@@ -856,8 +856,9 @@ def dispatch_linear(x, store, *, scalars=None, mesh=None, axis: str = "model",
     the shard_map'd fused kernel runs one program per macro column group —
     degrading internally to GSPMD when the store cannot shard or tile.
     Otherwise a warmed decoded-row cache (``serving_params(row_cache=True)``)
-    serves static reads as a plain matmul against ``store.cache`` — bitwise
-    identical to the fused kernel's single-K-tile grids — and the
+    serves static reads as a full-f32-precision matmul against
+    ``store.cache`` — on the CPU bitwise identical to the fused kernel's
+    single-K-tile grids — and the
     single-device fused Pallas kernel handles everything else, itself falling
     back to the packed-jnp reference for ``per_weight`` / non-fp16 stores.
     ``scalars`` (``cim_read.ops.make_scalars``) turns on per-read dynamic
@@ -875,7 +876,11 @@ def dispatch_linear(x, store, *, scalars=None, mesh=None, axis: str = "model",
     if scalars is None and store.cache is not None:
         b_shape = x.shape[:-1]
         x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
-        out = (x2 @ store.cache).reshape(*b_shape, store.shape[1])
+        # full f32 passes, as the kernel's dot: at the TPU's default matmul
+        # precision a single bf16 pass would round the fp16-exact weights
+        out = jnp.matmul(x2, store.cache,
+                         precision=jax.lax.Precision.HIGHEST)
+        out = out.reshape(*b_shape, store.shape[1])
         if with_info:
             return out, {"used_kernel": False, "sharded": False,
                          "route": "cached"}

@@ -212,9 +212,13 @@ class SecdedCode:
         position ``R[6:0]``, the overall-parity bit ``R[7]``, and the
         0/1/2 clean/corrected/uncorrectable status.
         """
+        return self.syndrome_words(bitpack.to_words(code_words))
+
+    def syndrome_words(self, cw):
+        """:meth:`syndrome_packed` on a word list (one array per code word
+        — the form the fused kernel carries, with no trailing word axis)."""
         r, n, Wd, Wc, hmask, _, body_mask, _, _, _ = \
             _secded_packed_tables(self.data_bits)
-        cw = [code_words[..., w].astype(jnp.uint32) for w in range(Wc)]
         body = [cw[w] & jnp.uint32(body_mask[w]) for w in range(Wc)]
         synd = [bitpack.masked_parity(body, hmask[j]) for j in range(r)]
         pos = synd[0]
@@ -237,9 +241,13 @@ class SecdedCode:
         :meth:`syndrome_packed`) and removes the parity-bit positions with
         static funnel shifts. Returns the packed data words.
         """
+        return bitpack.from_words(self.correct_extract_words(
+            bitpack.to_words(code_words), pos, parity))
+
+    def correct_extract_words(self, cw, pos, parity):
+        """:meth:`correct_extract_packed` on a word list -> data word list."""
         r, n, Wd, Wc, _, _, body_mask, _, data_mask, parity_pos0 = \
             _secded_packed_tables(self.data_bits)
-        cw = [code_words[..., w].astype(jnp.uint32) for w in range(Wc)]
         body = [cw[w] & jnp.uint32(body_mask[w]) for w in range(Wc)]
         single = parity == 1
         do_flip = single & (pos > 0)
@@ -252,8 +260,7 @@ class SecdedCode:
             body[w] = body[w] ^ flipw
         for pp in reversed(parity_pos0):          # descending 63, 31, ..., 0
             body = bitpack.delete_bit(body, pp)
-        data = [body[w] & jnp.uint32(data_mask[w]) for w in range(Wd)]
-        return bitpack.from_words(data)
+        return [body[w] & jnp.uint32(data_mask[w]) for w in range(Wd)]
 
     def decode_packed(self, code_words: jnp.ndarray
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -422,13 +429,28 @@ class One4NRowCodec:
         """Packed codewords [..., n_segments, codeword_words] ->
         (exp_row [..., row_weights], sign_words [..., sign_words],
         status [..., n_segments])."""
-        data, status = self.code.decode_packed(codewords)
-        pw = bitpack.zeros_like_words(data[..., 0, 0], self.payload_words)
-        for s in range(self.n_segments):
-            bitpack.or_window(pw, [data[..., s, w] for w in range(data.shape[-1])],
-                              s * self.segment_bits, self.segment_bits)
+        W = codewords.shape[-1]
+        pw, status = self.decode_words(
+            [[codewords[..., s, w].astype(jnp.uint32) for w in range(W)]
+             for s in range(self.n_segments)])
         exp_row, sign_words = self.split_payload_packed(pw)
-        return exp_row, sign_words, status
+        return exp_row, sign_words, jnp.stack(status, axis=-1)
+
+    def decode_words(self, segments):
+        """SECDED-decode one block's segments, each a list of codeword words
+        (one array per word), -> (payload word list, per-segment status
+        list). The array-free form :meth:`decode_packed` and the fused
+        kernel share."""
+        code = self.code
+        pw = bitpack.zeros_like_words(segments[0][0], self.payload_words)
+        status = []
+        for s, cw in enumerate(segments):
+            pos, parity, st = code.syndrome_words(cw)
+            data = code.correct_extract_words(cw, pos, parity)
+            bitpack.or_window(pw, data, s * self.segment_bits,
+                              self.segment_bits)
+            status.append(st)
+        return pw, status
 
 
 def residual_ber_after_secded(ber: float, codeword_bits: Optional[int] = None,

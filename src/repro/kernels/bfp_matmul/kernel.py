@@ -26,9 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _dequant_tile(man, exp, n_group: int):
     """man uint16 [bk, bn] (sign|mantissa), exp uint8 [bk//n_group, bn] -> f32."""
@@ -75,7 +72,7 @@ def bfp_matmul_pallas(x, man, exp, *, n_group: int = 8,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, man, exp)

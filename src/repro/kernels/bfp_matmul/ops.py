@@ -1,7 +1,7 @@
 """jit'd public wrapper for the BFP matmul kernel.
 
-``interpret`` defaults to True off-TPU (this container validates the kernel
-body on CPU); on a TPU runtime pass ``interpret=False`` for the Mosaic path.
+``interpret`` (see :func:`repro.kernels.resolve_interpret`) runs the kernel
+body on the CPU off-TPU; on a TPU the kernel always runs compiled by Mosaic.
 """
 from __future__ import annotations
 
@@ -11,12 +11,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.bfp_matmul.kernel import bfp_matmul_pallas
 from repro.kernels.bfp_matmul.ref import bfp_matmul_ref, dequant_ref, pack_bfp  # noqa: F401
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -28,8 +25,7 @@ def _round_up(x: int, m: int) -> int:
 def bfp_matmul(x, man, exp, *, n_group: int = 8, block_m: int = 128,
                block_n: int = 128, block_k: int = 512,
                interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     return bfp_matmul_pallas(x, man, exp, n_group=n_group, block_m=block_m,
                              block_n=block_n, block_k=block_k,
                              interpret=interpret)

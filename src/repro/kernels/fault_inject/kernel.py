@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def hash_u32(z: jnp.ndarray) -> jnp.ndarray:
     """murmur3 32-bit finalizer (wrapping uint32 arithmetic)."""
@@ -61,12 +58,23 @@ def _fault_kernel(bits_ref, o_ref, *, seed: int, threshold: int,
     o_ref[...] = bits_ref[...] ^ mask.astype(bits_ref.dtype)
 
 
-def _pick_block(dim: int, preferred: int) -> int:
-    """Largest divisor of ``dim`` not exceeding ``preferred``."""
-    for d in range(min(preferred, dim), 0, -1):
+def _pick_block(dim: int, preferred: int, quantum: int) -> int:
+    """Largest divisor of ``dim`` that is a multiple of ``quantum`` and not
+    above ``preferred``; the whole ``dim`` when there is none (Mosaic takes a
+    block dim that is either tile-aligned or the full array dim)."""
+    for d in range(min(preferred, dim) // quantum * quantum, 0, -quantum):
         if dim % d == 0:
             return d
     return dim
+
+
+def _pick_blocks(bits, block_r: int, block_c: int) -> Tuple[int, int]:
+    """Row/column block sizes in whole VMEM tiles of the plane's dtype:
+    128 lanes by 8 sublanes of 32-bit words (16 rows of 16-bit, 32 of
+    8-bit words)."""
+    rows = 8 * (4 // bits.dtype.itemsize)
+    return (_pick_block(bits.shape[0], block_r, rows),
+            _pick_block(bits.shape[1], block_c, 128))
 
 
 # The counter is a uint32 striding 32 per element, so streams repeat after
@@ -89,8 +97,7 @@ def fault_inject_pallas(bits: jnp.ndarray, *, seed: int, ber: float,
     """bits uint16 [R, C] -> bits with field positions flipped at rate ber."""
     r, c = bits.shape
     _check_counter_space(r, c)
-    block_r = _pick_block(r, block_r)
-    block_c = _pick_block(c, block_c)
+    block_r, block_c = _pick_blocks(bits, block_r, block_c)
     assert r % block_r == 0 and c % block_c == 0
     threshold = min(int(round(ber * 2 ** 32)), 2 ** 32 - 1)
     grid = (r // block_r, c // block_c)
@@ -102,7 +109,7 @@ def fault_inject_pallas(bits: jnp.ndarray, *, seed: int, ber: float,
         in_specs=[pl.BlockSpec((block_r, block_c), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(bits.shape, bits.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(bits)
@@ -186,8 +193,7 @@ def fault_inject_batched_pallas(bits: jnp.ndarray, seeds: jnp.ndarray,
     r, c = bits.shape
     t = seeds.shape[0]
     _check_counter_space(r, c)
-    block_r = _pick_block(r, block_r)
-    block_c = _pick_block(c, block_c)
+    block_r, block_c = _pick_blocks(bits, block_r, block_c)
     scalars = jnp.concatenate([
         jnp.asarray(threshold, jnp.uint32).reshape(1),
         jnp.asarray(m_thr, jnp.uint32).reshape(1),
@@ -204,7 +210,7 @@ def fault_inject_batched_pallas(bits: jnp.ndarray, seeds: jnp.ndarray,
                   pl.BlockSpec((block_r, block_c), lambda t, i, j: (i, j))],
         out_specs=pl.BlockSpec((1, block_r, block_c), lambda t, i, j: (t, i, j)),
         out_shape=jax.ShapeDtypeStruct((t, r, c), bits.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(scalars, bits)

@@ -7,13 +7,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bitops import FP16, FloatFormat
+from repro.kernels import resolve_interpret
 from repro.kernels.fault_inject.kernel import (fault_inject_batched_pallas,
                                                fault_inject_pallas)
 from repro.kernels.fault_inject.ref import fault_inject_ref  # noqa: F401
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def ber_to_threshold(ber) -> jnp.ndarray:
@@ -31,8 +28,7 @@ def ber_to_threshold(ber) -> jnp.ndarray:
                                              "interpret"))
 def fault_inject_bits(bits, *, seed: int, ber: float, positions,
                       interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     return fault_inject_pallas(bits, seed=seed, ber=ber,
                                positions=tuple(positions), interpret=interpret)
 
@@ -54,8 +50,7 @@ def fault_inject_bits_batched(bits, seeds, threshold, *, positions,
     inside the kernel (parameters ride in SMEM, so sweeping rate/length does
     not recompile either); drift pre-scales ``threshold`` by its tick.
     ``model=None`` / i.i.d. is bit-identical to the legacy stream."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     from repro.core import faultmodels as fm
     threshold = fm.compiled_threshold(model, threshold)
     m_thr, m_len = fm.model_scalars(model)

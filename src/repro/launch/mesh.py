@@ -8,19 +8,28 @@ import; everything else in the repo sees the real device count.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: the repo shards through GSPMD
+    (``with_sharding_constraint``, ``shard_map``), which needs Auto axes —
+    current jax defaults ``make_mesh`` to Explicit ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def make_trial_mesh(n_devices: int = 0):
@@ -31,7 +40,7 @@ def make_trial_mesh(n_devices: int = 0):
     mesh degenerates to fully-replicated execution at zero cost.
     """
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("trial",))
+    return auto_mesh((n,), ("trial",))
 
 
 def make_sweep_mesh(model_axis: int = 1, n_devices: int = 0):
@@ -44,4 +53,4 @@ def make_sweep_mesh(model_axis: int = 1, n_devices: int = 0):
     """
     n = n_devices or len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("trial", "model"))
+    return auto_mesh((n // model_axis, model_axis), ("trial", "model"))

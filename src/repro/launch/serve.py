@@ -60,6 +60,7 @@ from repro.core import deployment as dep_lib
 from repro.core.api import ReliabilityConfig
 from repro.data.synthetic import MarkovLM
 from repro.distributed import sharding as shlib
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.training import steps as steps_lib
 
@@ -347,7 +348,14 @@ def _serve_fleet(args, cfg, params):
     fresh single-replica fleet off the same spool and asserts its tokens and
     ECC stream match the routed run bitwise (the live replica-invariance
     probe).
+
+    ``params`` arrive on the host (see :func:`_serve`), and the routed fleet
+    is released before the probe builds its own: at published widths one
+    copy of a model fills a third of a chip, so the launch copy, replica 0
+    and the probe replica cannot all sit on device 0.
     """
+    import gc
+
     from repro.launch import engine as engine_lib
     from repro.launch import fleet as fleet_lib
 
@@ -373,9 +381,18 @@ def _serve_fleet(args, cfg, params):
     assert not incomplete, f"fleet dropped requests: {incomplete}"
     by_rep = " ".join(f"{k}={v}" for k, v in
                       sorted(agg["requests_by_replica"].items()))
+    # the device ids each replica's serving image occupies
+    replica_devices = {
+        name: sorted({d.id for leaf in jax.tree_util.tree_leaves(
+            rep.engine.params) for d in leaf.devices()})
+        for name, rep in fl.replicas.items()}
     print(f"fleet: {agg['n_requests']} requests over "
           f"{agg['n_replicas']} replicas x {args.slots} slots "
           f"(chunk {args.chunk}, max_len {max_len}); routed {by_rep}")
+    print(f"fleet: replica devices {replica_devices}")
+    spool_dir = fl.spool_dir
+    del fl
+    gc.collect()
     print(f"fleet: {agg['tok_s']:.1f} tok/s wall, "
           f"{agg['tok_s_virtual']:.1f} tok/s virtual "
           f"(busy wall {agg['busy_wall_s']:.2f}s of {agg['wall_s']:.2f}s); "
@@ -391,7 +408,7 @@ def _serve_fleet(args, cfg, params):
         pf = fleet_lib.Fleet.from_serving_params(
             cfg, params, n_replicas=1,
             meshes=meshes[:1] if meshes else None,
-            spool_dir=fl.spool_dir, prefix_cache=not args.no_prefix_cache,
+            spool_dir=spool_dir, prefix_cache=not args.no_prefix_cache,
             n_slots=args.slots, max_len=max_len, chunk=args.chunk,
             ecc_accounting=not args.no_ecc_accounting)
         pres, _ = pf.run(preq)
@@ -422,6 +439,7 @@ def _serve_fleet(args, cfg, params):
                        "prefix_cache": not args.no_prefix_cache},
             "aggregate": agg,
             "probe": probe,
+            "replica_devices": replica_devices,
             "requests": [results[r.rid].to_json() for r in requests],
         }
         with open(args.engine_json, "w") as f:
@@ -533,6 +551,7 @@ def main(argv=None):
                          "bitwise")
     args = ap.parse_args(argv)
     assert args.rounds >= 1, "--rounds must be >= 1"
+    compile_cache.configure()
 
     if args.fleet > 0:
         # per-replica meshes are built (and entered) inside the fleet; the
@@ -604,6 +623,8 @@ def _serve(args, mesh):
         params = place_on_mesh(params, mesh)
 
     if args.fleet > 0:
+        # the replicas hold the device copies; the launch copy waits on host
+        params = jax.device_get(params)
         return _serve_fleet(args, cfg, params)
 
     if args.engine:

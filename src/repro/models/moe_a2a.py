@@ -93,7 +93,6 @@ def _moe_a2a_local(router, w_gate, w_in, w_out, x_loc, cfg, ep: int,
 
 def apply_moe_a2a(params, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x [B,S,D] -> (out, aux). Requires an active mesh with "model"+"data"."""
-    from jax.experimental.shard_map import shard_map
     mesh = shlib.get_mesh()
     ep = mesh.shape["model"]
     b, s, d = x.shape
@@ -108,7 +107,7 @@ def apply_moe_a2a(params, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
     batch_axes = shlib.batch_axes()
     x_spec = P(batch_axes, "model", None)         # tokens: batch x seq sharded
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None),                  # router replicated
                   P("model", None, None),         # experts on "model", D full
@@ -116,7 +115,7 @@ def apply_moe_a2a(params, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
                   P("model", None, None),
                   x_spec),
         out_specs=(x_spec, P("model")),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], params["moe_wgate"], params["moe_win"],
       params["moe_wout"], x)
     return out, jnp.mean(aux)

@@ -472,6 +472,7 @@ _MESH_IDENTITY_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     from repro import CIMDeployment, PolicyRule, ReliabilityPolicy
     from repro.core import cim
@@ -494,7 +495,7 @@ _MESH_IDENTITY_SCRIPT = textwrap.dedent("""
     ref_faulty = ref.inject(key, 2e-3)
     ref_params, ref_stats = ref_faulty.read()
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     dep = CIMDeployment.deploy(params, policy).shard(mesh)
     inject = jax.jit(lambda d, k: d.inject(k, 2e-3))
     faulty = inject(dep, key)

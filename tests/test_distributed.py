@@ -107,6 +107,7 @@ _RESHARD_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, sys
     import jax
+    from repro.launch.mesh import auto_mesh
     import jax.numpy as jnp
     from repro.configs import RunConfig, get_config
     from repro.data.synthetic import MarkovLM
@@ -119,7 +120,7 @@ _RESHARD_SCRIPT = textwrap.dedent("""
     cfg = get_config("olmo-1b").reduced()
     run = RunConfig(arch="olmo-1b", steps=2, remat=False)
     # Phase 1: train on a (4, 2) mesh, checkpoint.
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = auto_mesh((4, 2), ("data", "model"))
     shlib.set_mesh(mesh_a)
     with mesh_a:
         state = steps.init_train_state(jax.random.PRNGKey(0), cfg, run)
@@ -131,8 +132,8 @@ _RESHARD_SCRIPT = textwrap.dedent("""
         ckpt.save(state, 1, ckdir)
 
     # Phase 2: "two hosts failed" -> shrink to a (2, 2) mesh, restore, resume.
-    mesh_b = jax.make_mesh((2, 2), ("data", "model"),
-                           devices=jax.devices()[:4])
+    mesh_b = auto_mesh((2, 2), ("data", "model"),
+                       devices=jax.devices()[:4])
     shlib.set_mesh(mesh_b)
     with mesh_b:
         abstract = jax.eval_shape(
@@ -168,13 +169,14 @@ _A2A_MOE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.distributed import sharding as shlib
     from repro.models import moe as moe_lib
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     shlib.set_mesh(mesh)
     cfg = get_config("qwen3-moe-235b-a22b").reduced()
     cfg = dataclasses.replace(cfg, d_model=64, n_experts=8, top_k=2,

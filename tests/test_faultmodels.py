@@ -248,6 +248,7 @@ _SHARDED_MODEL_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     from repro.core import align, cim
     from repro.core import faultmodels as fm
@@ -256,9 +257,9 @@ _SHARDED_MODEL_SCRIPT = textwrap.dedent("""
     w = jax.random.normal(jax.random.PRNGKey(0), (128, 128)) * 0.1
     w_al, _ = align.align_matrix(w, align.AlignmentConfig(8, 2))
     store = cim.pack(w_al, cim.CIMConfig(protect="one4n"))
-    meshes = [jax.make_mesh((2,), ("model",)),
-              jax.make_mesh((8,), ("model",)),
-              jax.make_mesh((2, 4), ("data", "model"))]
+    meshes = [auto_mesh((2,), ("model",)),
+              auto_mesh((8,), ("model",)),
+              auto_mesh((2, 4), ("data", "model"))]
     models = [fm.FaultProcess.burst(rate=0.4, length=4, axis="row"),
               fm.FaultProcess.burst(rate=0.4, length=8, axis="col"),
               dataclasses.replace(fm.FaultProcess.drift(drift_rate=0.3),

@@ -31,6 +31,7 @@ from repro.kernels.cim_read import ops as cr_ops
 from repro.kernels.fault_inject.ops import ber_to_threshold
 from repro.launch import engine as engine_lib
 from repro.launch import serve as serve_lib
+from repro.launch.mesh import auto_mesh
 from repro.models import lm
 
 
@@ -147,7 +148,7 @@ def test_serving_policy_embed_packed_unembed_cached():
 def test_shard_and_derived_copies_no_stale_cache():
     dep = _dep()
     sp = dep.serving_params()
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = auto_mesh((1,), ("model",))
     dep_sh = dep.shard(mesh)
     for _, _, s in dep_sh.store_leaves():
         assert s.cache is None, "shard() must not inherit a serving cache"

@@ -39,6 +39,7 @@ _INJECT_EQUIV_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     from repro.core import align, cim
     from repro.kernels.cim_read import ops as cr_ops
@@ -52,8 +53,8 @@ _INJECT_EQUIV_SCRIPT = textwrap.dedent("""
     w = jax.random.normal(jax.random.PRNGKey(0), (128, 128)) * 0.1
     w_al, _ = align.align_matrix(w, align.AlignmentConfig(8, 2))
     w16 = jnp.asarray(jnp.asarray(w, jnp.float16), jnp.float32)
-    meshes = [jax.make_mesh((2,), ("model",)),
-              jax.make_mesh((2, 4), ("data", "model"))]
+    meshes = [auto_mesh((2,), ("model",)),
+              auto_mesh((2, 4), ("data", "model"))]
 
     def plane_equal(a, b):
         for name, p in cim._plane_dict(a).items():
@@ -116,6 +117,7 @@ _TILE_STREAM_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     import numpy as np
     from repro.core import align, cim
     from repro.kernels.cim_read import ops as cr_ops
@@ -134,7 +136,7 @@ _TILE_STREAM_SCRIPT = textwrap.dedent("""
     sc = cr_ops.make_scalars(seeds, thr, thr)
     host = cim.inject_with_seeds(store, seeds, thr, thr)
     x = jax.random.normal(jax.random.PRNGKey(4), (8, 256))
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     checked = []
     # every autotuned tile combo, both shard layouts: the per-shard kernels
     # must draw flip streams at GLOBAL store coordinates (SCALAR_OFF_K/J
