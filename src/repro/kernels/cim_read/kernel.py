@@ -413,6 +413,7 @@ def _call(kernel_kw, planes, plane_specs, x, man, scalars, *, m, n, k,
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
+        name="cim_read",
     )(scalars, x, man, *planes)
 
 

@@ -112,6 +112,7 @@ def fault_inject_pallas(bits: jnp.ndarray, *, seed: int, ber: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="fault_inject",
     )(bits)
 
 
@@ -213,4 +214,5 @@ def fault_inject_batched_pallas(bits: jnp.ndarray, seeds: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="fault_inject_trials",
     )(scalars, bits)

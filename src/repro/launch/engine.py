@@ -79,6 +79,23 @@ per read, or the per-(request, position) dynamically-faulted image when a
 ``_cim`` runtime rides in params). Aggregate: tok/s over the decode loop and
 per-slot occupancy.
 
+**Tracing.** The engine writes its spans with ``jax.profiler``, so they sit
+on the device trace's clock and record only while a trace is active (about
+a microsecond each otherwise). Counters ride on the spans as arguments,
+all known on the host when the span opens; the tree, with each span's
+arguments:
+
+* ``engine.step`` — one call of :meth:`Engine.step`;
+* ``engine.admit`` — ``rid``, ``queue_ms`` (admission start minus submit
+  time);
+* ``engine.prefill`` — one chunk's dispatch: ``rid``, ``pos``, ``length``;
+* ``engine.decode`` — the slot batch's dispatch: ``active``;
+* ``engine.wait`` / ``engine.copy`` — waiting for the logits, then their
+  device-to-host copy, where the host blocks anyway: ``of`` (``decode`` or
+  ``prefill``);
+* ``engine.charge_reads`` — ``rid``, ``pos``;
+* ``engine.evict`` — ``rid``.
+
 ``LoadGen`` drives the engine open-loop: Poisson arrivals at ``rate`` req/s
 (arrivals are wall-clock gated, independent of service) with uniform prompt
 and generation length ranges.
@@ -94,6 +111,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import cim as cim_lib
@@ -505,20 +523,22 @@ class Engine:
         totals (the ScrubPolicy threshold signal)."""
         if not self._ecc_fns:
             return
-        slot.ecc["reads"] += 1
-        corr = unc = 0
-        for pstr, fn in self._ecc_fns:
-            c, u = fn(jnp.uint32(salt), jnp.int32(pos))
-            corr += c
-            unc += u
-            store = self.store_ecc[pstr]
-            store["reads"] += 1
-            store["corrected"] += c
-            store["uncorrectable"] += u
-        slot.ecc["corrected"] += corr
-        slot.ecc["uncorrectable"] += unc
-        slot.ecc_window.append({"pos": int(pos), "reads": 1,
-                                "corrected": corr, "uncorrectable": unc})
+        with TraceAnnotation("engine.charge_reads", rid=slot.rid,
+                             pos=int(pos)):
+            slot.ecc["reads"] += 1
+            corr = unc = 0
+            for pstr, fn in self._ecc_fns:
+                c, u = fn(jnp.uint32(salt), jnp.int32(pos))
+                corr += c
+                unc += u
+                store = self.store_ecc[pstr]
+                store["reads"] += 1
+                store["corrected"] += c
+                store["uncorrectable"] += u
+            slot.ecc["corrected"] += corr
+            slot.ecc["uncorrectable"] += unc
+            slot.ecc_window.append({"pos": int(pos), "reads": 1,
+                                    "corrected": corr, "uncorrectable": unc})
 
     # ------------------------------------------------------------ scheduling
 
@@ -547,83 +567,90 @@ class Engine:
             raise EngineError(
                 f"request {req.rid}: prompt {plen} + max_new {req.max_new} "
                 f"exceeds the engine's max_len {self.max_len}")
-        rsalt = np.uint32(dep_lib.request_salt(req.rid))
         # admit_t comes from the wall clock, never the admission gate `now`
         # (a closed-loop run gates with now=inf — that must not leak into
         # queue_s or the JSON artifact)
-        slot = _Slot(rid=req.rid, prompt_len=plen, max_new=req.max_new,
-                     submit_t=submit_t, admit_t=self._clock(), req=req,
-                     salt=int(rsalt))
-        # walk the trie over the prompt's full LEADING chunks (never the
-        # final one — its logits are the first token, so it must run);
-        # `prefill_chunk` masks off the explicit pos argument and the
-        # always-cold final chunk leaves caches['pos'][slot] = plen, so
-        # injection only has to land the state chunk (KV rows, or the
-        # post-chunk snapshot for fold/window kinds — deepest hit wins)
-        starts = list(range(0, plen, self.chunk))
-        node = None
-        pos = 0
-        if self.prefix_cache is not None:
-            for c0 in starts[:-1]:
+        admit_t = self._clock()
+        with TraceAnnotation("engine.admit", rid=req.rid,
+                             queue_ms=1e3 * (admit_t - submit_t)):
+            rsalt = np.uint32(dep_lib.request_salt(req.rid))
+            slot = _Slot(rid=req.rid, prompt_len=plen, max_new=req.max_new,
+                         submit_t=submit_t, admit_t=admit_t, req=req,
+                         salt=int(rsalt))
+            # walk the trie over the prompt's full LEADING chunks (never the
+            # final one — its logits are the first token, so it must run);
+            # `prefill_chunk` masks off the explicit pos argument and the
+            # always-cold final chunk leaves caches['pos'][slot] = plen, so
+            # injection only has to land the state chunk (KV rows, or the
+            # post-chunk snapshot for fold/window kinds — deepest hit wins)
+            starts = list(range(0, plen, self.chunk))
+            node = None
+            pos = 0
+            if self.prefix_cache is not None:
+                for c0 in starts[:-1]:
+                    seg = req.tokens[c0:c0 + self.chunk]
+                    hit = self.prefix_cache.lookup(node, seg)
+                    if hit is None:
+                        break
+                    self.caches = self._inject(
+                        self.caches, jnp.int32(slot_idx), jnp.int32(c0),
+                        hit.state)
+                    # replay the ECC accounting of the read this chunk's cold
+                    # prefill would have issued — same salt, same read index
+                    self._charge_reads(slot, np.uint32(hit.salt), c0)
+                    node = hit
+                    pos = c0 + self.chunk
+            slot.prefix_tokens = pos
+            logits = None
+            for c0 in range(pos, plen, self.chunk):
                 seg = req.tokens[c0:c0 + self.chunk]
-                hit = self.prefix_cache.lookup(node, seg)
-                if hit is None:
-                    break
-                self.caches = self._inject(
-                    self.caches, jnp.int32(slot_idx), jnp.int32(c0),
-                    hit.state)
-                # replay the ECC accounting of the read this chunk's cold
-                # prefill would have issued — same salt, same read index
-                self._charge_reads(slot, np.uint32(hit.salt), c0)
-                node = hit
-                pos = c0 + self.chunk
-        slot.prefix_tokens = pos
-        logits = None
-        for c0 in range(pos, plen, self.chunk):
-            seg = req.tokens[c0:c0 + self.chunk]
-            length = seg.size
-            csalt = np.uint32(dep_lib.prefix_salt(req.tokens[:c0 + length]))
-            # the ragged tail pads only to what still fits under max_len
-            # (padding row writes must not clamp back over prompt rows);
-            # pad length never enters the fault-stream chain
-            pad_to = min(self.chunk, self.max_len - c0)
-            padded = np.pad(seg, (0, pad_to - length))
-            logits, self.caches = self._prefill(
-                self.params, self.caches, jnp.asarray(padded),
-                jnp.int32(slot_idx), jnp.int32(c0), jnp.int32(length),
-                jnp.uint32(csalt))
-            self._charge_reads(slot, csalt, c0)
-            if self.prefix_cache is not None and length == self.chunk:
-                state = self._extract(self.caches, jnp.int32(slot_idx),
-                                      jnp.int32(c0), self.chunk)
-                node = self.prefix_cache.insert(node, seg, state, csalt)
-        logits = np.asarray(logits)
-        self._check(logits, slot)
-        tok = int(np.argmax(logits))
-        slot.tokens.append(tok)
-        if self.collect_logits:
-            slot.logits.append(logits)
-        slot.ttft_s = self._clock() - submit_t
-        self.slots[slot_idx] = slot
-        self._tokens[slot_idx, 0] = tok
-        self._salts[slot_idx] = rsalt
+                length = seg.size
+                csalt = np.uint32(
+                    dep_lib.prefix_salt(req.tokens[:c0 + length]))
+                # the ragged tail pads only to what still fits under max_len
+                # (padding row writes must not clamp back over prompt rows);
+                # pad length never enters the fault-stream chain
+                pad_to = min(self.chunk, self.max_len - c0)
+                padded = np.pad(seg, (0, pad_to - length))
+                with TraceAnnotation("engine.prefill", rid=req.rid, pos=c0,
+                                     length=length):
+                    logits, self.caches = self._prefill(
+                        self.params, self.caches, jnp.asarray(padded),
+                        jnp.int32(slot_idx), jnp.int32(c0), jnp.int32(length),
+                        jnp.uint32(csalt))
+                self._charge_reads(slot, csalt, c0)
+                if self.prefix_cache is not None and length == self.chunk:
+                    state = self._extract(self.caches, jnp.int32(slot_idx),
+                                          jnp.int32(c0), self.chunk)
+                    node = self.prefix_cache.insert(node, seg, state, csalt)
+            logits = self._fetch(logits, "prefill")
+            self._check(logits, slot)
+            tok = int(np.argmax(logits))
+            slot.tokens.append(tok)
+            if self.collect_logits:
+                slot.logits.append(logits)
+            slot.ttft_s = self._clock() - submit_t
+            self.slots[slot_idx] = slot
+            self._tokens[slot_idx, 0] = tok
+            self._salts[slot_idx] = rsalt
 
     def _evict(self, slot_idx: int, finish: str) -> None:
         slot = self.slots[slot_idx]
-        res = RequestResult(
-            rid=slot.rid, prompt_len=slot.prompt_len, tokens=slot.tokens,
-            finish=finish, queue_s=slot.admit_t - slot.submit_t,
-            ttft_s=slot.ttft_s, decode_s=slot.decode_s, slot=slot_idx,
-            ecc=slot.ecc, finite=slot.finite,
-            logits=np.stack(slot.logits) if slot.logits else None,
-            replica=self.replica, prefix_tokens=slot.prefix_tokens,
-            salt=slot.salt, ecc_window=slot.ecc_window, scrubs=slot.scrubs)
-        self.results[slot.rid] = res
-        self.slots[slot_idx] = None
-        # reset the slot's position so the next admission prefills from 0;
-        # stale KV/ring rows stay causally masked until overwritten, and
-        # prefill_chunk zeroes fold states (rwkv/rec) at pos == 0
-        self.caches["pos"] = self.caches["pos"].at[slot_idx].set(0)
+        with TraceAnnotation("engine.evict", rid=slot.rid):
+            res = RequestResult(
+                rid=slot.rid, prompt_len=slot.prompt_len, tokens=slot.tokens,
+                finish=finish, queue_s=slot.admit_t - slot.submit_t,
+                ttft_s=slot.ttft_s, decode_s=slot.decode_s, slot=slot_idx,
+                ecc=slot.ecc, finite=slot.finite,
+                logits=np.stack(slot.logits) if slot.logits else None,
+                replica=self.replica, prefix_tokens=slot.prefix_tokens,
+                salt=slot.salt, ecc_window=slot.ecc_window, scrubs=slot.scrubs)
+            self.results[slot.rid] = res
+            self.slots[slot_idx] = None
+            # reset the slot's position so the next admission prefills from 0;
+            # stale KV/ring rows stay causally masked until overwritten, and
+            # prefill_chunk zeroes fold states (rwkv/rec) at pos == 0
+            self.caches["pos"] = self.caches["pos"].at[slot_idx].set(0)
 
     def _check(self, logits: np.ndarray, slot: _Slot) -> None:
         """Record the slot's actual finiteness verdict (the JSON artifact
@@ -633,6 +660,15 @@ class Engine:
             if self.check_finite:
                 raise EngineError(
                     f"non-finite logits serving request {slot.rid}")
+
+    @staticmethod
+    def _fetch(logits, of: str) -> np.ndarray:
+        """Wait for the logits, then copy them to the host: where the host
+        blocks anyway, split into the wait for the program and the copy."""
+        with TraceAnnotation("engine.wait", of=of):
+            jax.block_until_ready(logits)
+        with TraceAnnotation("engine.copy", of=of):
+            return np.asarray(logits)
 
     def _clock(self) -> float:
         return time.perf_counter() - self._t0
@@ -718,6 +754,10 @@ class Engine:
         """Admit arrived requests into free slots, then advance every active
         slot by one token. Returns an event dict (admitted/decoded/evicted
         rids, ``idle`` when there was nothing to do)."""
+        with TraceAnnotation("engine.step"):
+            return self._step(now)
+
+    def _step(self, now: Optional[float]) -> dict:
         if not hasattr(self, "_t0"):
             self._t0 = time.perf_counter()
         if now is None:
@@ -743,14 +783,15 @@ class Engine:
                     "decoded": []}
 
         t0 = time.perf_counter()
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(self._tokens),
-            jnp.asarray(active), jnp.asarray(self._salts))
-        logits = np.asarray(logits)
+        n_active = int(active.sum())
+        with TraceAnnotation("engine.decode", active=n_active):
+            logits, self.caches = self._decode(
+                self.params, self.caches, jnp.asarray(self._tokens),
+                jnp.asarray(active), jnp.asarray(self._salts))
+        logits = self._fetch(logits, "decode")
         dt = time.perf_counter() - t0
         self.steps += 1
         decoded = []
-        n_active = int(active.sum())
         for i in np.flatnonzero(active):
             slot = self.slots[i]
             self._check(logits[i], slot)

@@ -786,10 +786,11 @@ def prefill_chunk(params, cfg: ModelConfig, caches, tokens, slot, pos,
     pos = jnp.asarray(pos, jnp.int32)
     dt = cfg.cdtype()
     toks = tokens[None]                                       # [1, C]
-    if isinstance(params["embed"], cim_lib.CIMStore):
-        x = _embed_lookup(params, cfg, toks, pos=pos, req_salt=req_salt)
-    else:
-        x = params["embed"].astype(dt)[toks]
+    with jax.named_scope("embed"):
+        if isinstance(params["embed"], cim_lib.CIMStore):
+            x = _embed_lookup(params, cfg, toks, pos=pos, req_salt=req_salt)
+        else:
+            x = params["embed"].astype(dt)[toks]
     x = shard(x, "batch", None, None)
     sub = slot_caches(caches, slot)
     if any(s.fold_state for s in slot_state_specs(cfg)):
@@ -801,9 +802,12 @@ def prefill_chunk(params, cfg: ModelConfig, caches, tokens, slot, pos,
             return jax.tree_util.tree_map(
                 lambda a: jnp.where(fresh, jnp.zeros_like(a), a), c)
         sub = _map_block_states(cfg, sub, reset)
-    x, gc, tc = _decode_stack(params, cfg, sub, x, pos, length=length)
+    with jax.named_scope("blocks"):
+        x, gc, tc = _decode_stack(params, cfg, sub, x, pos, length=length)
     h = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)  # [1,1,D]
-    logits = _unembed_logits(params, h, pos=pos, req_salt=req_salt)[:, 0]
+    with jax.named_scope("unembed"):
+        logits = _unembed_logits(params, h, pos=pos,
+                                 req_salt=req_salt)[:, 0]
     out = merge_slot_caches(caches, slot, {"groups": gc, "tail": tc})
     out["pos"] = caches["pos"].at[slot].set(pos + length)
     return logits[0], out
@@ -843,16 +847,19 @@ def decode_slots(params, cfg: ModelConfig, caches, tokens, active,
             "but no req_salts — per-read seeds would alias across requests; "
             "pass deployment.request_salt(rid) per slot")
     emb = params["embed"]
-    if isinstance(emb, cim_lib.CIMStore) and dynamic:
-        x = jnp.concatenate(
-            [_embed_lookup(params, cfg, tokens[i:i + 1], pos=pos[i],
-                           req_salt=req_salts[i]) for i in range(s)], axis=0)
-    elif isinstance(emb, cim_lib.CIMStore):
-        x = _embed_lookup(params, cfg, tokens)
-    else:
-        x = emb.astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        if isinstance(emb, cim_lib.CIMStore) and dynamic:
+            x = jnp.concatenate(
+                [_embed_lookup(params, cfg, tokens[i:i + 1], pos=pos[i],
+                               req_salt=req_salts[i]) for i in range(s)],
+                axis=0)
+        elif isinstance(emb, cim_lib.CIMStore):
+            x = _embed_lookup(params, cfg, tokens)
+        else:
+            x = emb.astype(dt)[tokens]
     x = shard(x, "batch", None, None)
-    x, gc, tc = _decode_stack(params, cfg, caches, x, pos)
+    with jax.named_scope("blocks"):
+        x, gc, tc = _decode_stack(params, cfg, caches, x, pos)
     if any(sp.fold_state for sp in slot_state_specs(cfg)):
         act = jnp.asarray(active, bool)
 
@@ -874,12 +881,13 @@ def decode_slots(params, cfg: ModelConfig, caches, tokens, active,
                                           caches["tail"][i])
                    if slot_state_spec(kind).fold_state else tc[i]
                    for i, kind in enumerate(tail_kinds))
-    if isinstance(params["unembed"], cim_lib.CIMStore) and dynamic:
-        logits = jnp.concatenate(
-            [_unembed_logits(params, x[i:i + 1], pos=pos[i],
-                             req_salt=req_salts[i]) for i in range(s)],
-            axis=0)[:, 0]
-    else:
-        logits = _unembed_logits(params, x)[:, 0]
+    with jax.named_scope("unembed"):
+        if isinstance(params["unembed"], cim_lib.CIMStore) and dynamic:
+            logits = jnp.concatenate(
+                [_unembed_logits(params, x[i:i + 1], pos=pos[i],
+                                 req_salt=req_salts[i]) for i in range(s)],
+                axis=0)[:, 0]
+        else:
+            logits = _unembed_logits(params, x)[:, 0]
     return logits, {"groups": gc, "tail": tc,
                     "pos": pos + active.astype(jnp.int32)}
