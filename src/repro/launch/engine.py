@@ -459,11 +459,14 @@ class Engine:
     # ------------------------------------------------------------ ECC
 
     def _build_ecc_fns(self):
-        """One per-read ECC accountant per deployed store leaf.
+        """One per-read ECC accountant per deployed store leaf, called with
+        host values: ``fn(salt, pos) -> (corrected, uncorrectable)``.
 
         Static image: the macro's corrected/uncorrectable counts are a
-        constant of the image — computed once, charged per read. Dynamic
-        runtime: a jitted fn re-derives the (request, position) flip streams
+        constant of the image — computed once, charged per read as plain
+        Python, with no device work. Dynamic runtime (the only accountant
+        that runs on the device): a jitted fn, fed ``np.uint32`` /
+        ``np.int32`` scalars, re-derives the (request, position) flip streams
         (the exact chain the model's reads use) and counts the ECC events of
         that read's faulted image. That re-derivation decodes the FULL
         codeword planes per active slot per step (the serving read itself
@@ -509,14 +512,17 @@ class Engine:
                     st = cim_lib.store_stats(faulted)
                     return jnp.stack([st["corrected"], st["uncorrectable"]])
                 jfn = jax.jit(dyn)
-                fns.append((pstr, lambda req_salt, pos, f=jfn:
-                            tuple(int(v)
-                                  for v in np.asarray(f(req_salt, pos)))))
+                fns.append((pstr, lambda req_salt, pos, f=jfn: tuple(
+                    int(v) for v in np.asarray(
+                        f(np.uint32(req_salt), np.int32(pos))))))
         return fns
 
     def _charge_reads(self, slot: _Slot, salt, pos: int) -> None:
         """Charge one CIM read (all deployed macros) at read index ``pos``.
 
+        ``salt`` and ``pos`` are host values and go to each store's
+        accountant as they are: a static image's charge is host arithmetic
+        alone, and only a dynamic image's accountant runs on the device.
         Besides the request's cumulative counters, every charge lands in the
         request's ``ecc_window`` time series (one row per decode chunk, the
         scrub-decision observable) and the engine's per-store ``store_ecc``
@@ -528,7 +534,7 @@ class Engine:
             slot.ecc["reads"] += 1
             corr = unc = 0
             for pstr, fn in self._ecc_fns:
-                c, u = fn(jnp.uint32(salt), jnp.int32(pos))
+                c, u = fn(salt, pos)
                 corr += c
                 unc += u
                 store = self.store_ecc[pstr]

@@ -93,6 +93,88 @@ def test_batch_invariance(setup, inject, serve_path):
         assert co[rid].ecc == solo[rid].ecc, (inject, serve_path, rid)
 
 
+class _NoDevice:
+    """Stands in for ``jax`` / ``jax.numpy``: any use is a failure."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"static ECC charge touched jax ({name})")
+
+
+def test_static_charge_runs_no_device_program(setup, monkeypatch):
+    """A static image's ECC counts are constants of the image: a charge is
+    host arithmetic alone — it reaches no ``jax``/``jnp`` name of the engine
+    and moves nothing to the device — and adds the per-store
+    ``store_stats`` constants once per read, for decode and prefill reads."""
+    from repro.core import cim as cim_lib
+    from repro.core import deployment as dep_lib
+    cfg, params, dkey = setup
+    sparams = _serving_params(params, dkey, inject="static",
+                              serve_path="fused")
+    eng = engine_lib.Engine(cfg, sparams, n_slots=SLOTS, max_len=MAX_LEN,
+                            chunk=CHUNK)
+    const = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            sparams, is_leaf=cim_lib._is_store)[0]:
+        if cim_lib._is_store(leaf):
+            st = cim_lib.store_stats(leaf)
+            const[dep_lib.path_str(path)] = (int(st["corrected"]),
+                                             int(st["uncorrectable"]))
+    assert const and [p for p, _ in eng._ecc_fns] == list(const)
+    rsalt = np.uint32(dep_lib.request_salt(7))
+    csalt = np.uint32(dep_lib.prefix_salt(np.arange(CHUNK, dtype=np.int32)))
+    slot = engine_lib._Slot(rid=7, prompt_len=CHUNK, max_new=4, submit_t=0.0,
+                            admit_t=0.0, salt=int(rsalt))
+    reads = [(csalt, 0), (rsalt, CHUNK), (rsalt, CHUNK + 1)]
+    monkeypatch.setattr(engine_lib, "jnp", _NoDevice())
+    monkeypatch.setattr(engine_lib, "jax", _NoDevice())
+    with jax.transfer_guard("disallow"):
+        for salt, pos in reads:
+            eng._charge_reads(slot, salt, pos)
+    corr = sum(c for c, _ in const.values())
+    unc = sum(u for _, u in const.values())
+    n = len(reads)
+    assert slot.ecc == {"reads": n, "corrected": n * corr,
+                        "uncorrectable": n * unc}
+    assert slot.ecc_window == [{"pos": pos, "reads": 1, "corrected": corr,
+                                "uncorrectable": unc} for _, pos in reads]
+    assert eng.store_ecc == {p: {"reads": n, "corrected": n * c,
+                                 "uncorrectable": n * u}
+                             for p, (c, u) in const.items()}
+
+
+# per request: ecc, then (pos, corrected, uncorrectable) per charged read of
+# the dynamic image — counts the accountant gave when it was fed device
+# scalars; host scalars must leave every one bit-identical
+_DYNAMIC_ECC = {
+    0: ({"reads": 6, "corrected": 605, "uncorrectable": 45},
+        [(0, 92, 11), (8, 89, 8), (11, 111, 6), (12, 102, 4), (13, 95, 6),
+         (14, 116, 10)]),
+    1: ({"reads": 3, "corrected": 314, "uncorrectable": 9},
+        [(0, 101, 5), (7, 110, 2), (8, 103, 2)]),
+    2: ({"reads": 4, "corrected": 410, "uncorrectable": 18},
+        [(0, 99, 4), (8, 108, 3), (12, 97, 6), (13, 106, 5)]),
+    3: ({"reads": 6, "corrected": 603, "uncorrectable": 39},
+        [(0, 100, 8), (8, 101, 1), (10, 110, 6), (11, 98, 8), (12, 81, 6),
+         (13, 113, 10)]),
+}
+
+
+def test_dynamic_ecc_counts_pinned(setup):
+    """The dynamic accountant's per-request and per-store counts on a fixed
+    image and load, pinned to recorded values."""
+    cfg, params, dkey = setup
+    sparams = _serving_params(params, dkey, inject="dynamic",
+                              serve_path="fused")
+    res, agg = _run(cfg, sparams, _requests())
+    got = {rid: (r.ecc, [(w["pos"], w["corrected"], w["uncorrectable"])
+                         for w in r.ecc_window])
+           for rid, r in res.items()}
+    assert got == _DYNAMIC_ECC
+    assert agg["store_ecc"] == {
+        "embed": {"reads": 19, "corrected": 951, "uncorrectable": 55},
+        "unembed": {"reads": 19, "corrected": 981, "uncorrectable": 56}}
+
+
 def test_invariance_across_slot_assignment(setup):
     """The slot a request lands on must not enter its fault streams: reverse
     the submission order (so every request gets a different slot) and demand
